@@ -1,8 +1,9 @@
 """Vocab-sharded merge bench: ragged segmented launch, 8-way V slices.
 
-Forks one subprocess with ``--xla_force_host_platform_device_count=8``
-(the parent keeps the single real CPU device for the other sections)
-and ``MLEGO_KERNEL_INTERPRET=1``, merges one ragged batch through the
+Forks one subprocess pinned to the CPU (``JAX_PLATFORMS=cpu``, so it
+never contends for an accelerator the parent holds) with
+``--xla_force_host_platform_device_count=8`` and
+``MLEGO_KERNEL_INTERPRET=1``, merges one ragged batch through the
 single-device ``DeviceBackend`` and the vocab-sharded
 ``ShardedDeviceBackend``, and reports launches, pad rows, per-device
 resident bytes and wall time for each.  On CPU the walls measure the
@@ -67,6 +68,7 @@ def run(quick: bool = False) -> dict:
     k, v = (8, 512) if quick else (16, 2048)
     counts = [1, 1, 4, 1] if quick else [1, 3, 1, 8, 2, 1]
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["MLEGO_KERNEL_INTERPRET"] = "1"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")

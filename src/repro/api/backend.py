@@ -56,7 +56,8 @@ from repro.api.trainers import (
     merge_family_name,
 )
 from repro.configs.lda_default import LDAConfig
-from repro.core.errors import DeviceLostError
+from repro.core.errors import (DeviceLostError, ExecutionError,
+                               PermanentExecutionError)
 from repro.core.lda import MaterializedModel
 from repro.core.merge import (
     device_merge_params,
@@ -83,15 +84,17 @@ from repro.testing.faults import maybe_fail
 
 BACKEND_NAMES = ("host", "device", "device_sharded")
 
-# Runtime errors the device toolchain raises when an accelerator dies
-# mid-launch (OOM, halted device, failed transfer).  Translated to
-# ``DeviceLostError`` so callers can quarantine the backend and replay
-# on the fallback chain instead of failing the query.
-_JAX_RUNTIME_ERRORS = tuple(
-    t for t in (getattr(getattr(jax, "errors", None),
-                        "JaxRuntimeError", None),
-                getattr(jax.lib, "XlaRuntimeError", None))
-    if isinstance(t, type))
+# Status-message markers of a program the chip's compiler refused: a
+# Mosaic kernel that does not compile, a kernel over its scoped VMEM
+# (only known at compile time), an XLA:TPU compile failure.
+_COMPILE_FAILURE_MARKERS = ("Mosaic failed to compile",
+                            "memory space vmem",
+                            "compile permanent error")
+
+
+def _is_compile_failure(exc: jax.errors.JaxRuntimeError) -> bool:
+    msg = str(exc)
+    return any(m in msg for m in _COMPILE_FAILURE_MARKERS)
 
 
 @dataclass(frozen=True)
@@ -170,16 +173,32 @@ class ExecutionBackend:
 
     @contextmanager
     def _device_guard(self):
-        """Translate raw runtime crashes into ``DeviceLostError`` so
-        the caller knows the *backend* is suspect, not the query."""
+        """Type what a device launch raises.
+
+        A runtime crash (halted device, failed transfer, device OOM)
+        becomes ``DeviceLostError``: the *backend* is suspect, not the
+        query, so the session quarantines it and replays on the
+        fallback chain.  A kernel that fails to trace, lower or compile
+        is deterministic: it becomes ``PermanentExecutionError``, which
+        is neither retried nor replayed on another backend — a host
+        answer would hide that the device path is broken.  Typed errors
+        and I/O errors (injected faults, store reads) pass through."""
         try:
             yield
-        except DeviceLostError:
+        except (ExecutionError, OSError):
             raise
-        except _JAX_RUNTIME_ERRORS as exc:
+        except jax.errors.JaxRuntimeError as exc:
+            if _is_compile_failure(exc):
+                raise PermanentExecutionError(
+                    f"{self.name} backend: kernel failed to compile: "
+                    f"{exc}") from exc
             raise DeviceLostError(
                 f"{self.name} backend lost its device: {exc}",
                 backend=self.name) from exc
+        except Exception as exc:
+            raise PermanentExecutionError(
+                f"{self.name} backend: kernel failed to lower: "
+                f"{type(exc).__name__}: {exc}") from exc
 
     # -- lifecycle -------------------------------------------------------
     def bind_store(self, store: ModelStore) -> None:
@@ -564,7 +583,7 @@ class DeviceBackend(ExecutionBackend):
         from repro.core.vb import vb_fit
         t0 = time.perf_counter()
         x = doc_term_matrix(corpus)
-        with self._annotate("mlego.vb_estep"):
+        with self._device_guard(), self._annotate("mlego.vb_estep"):
             lam = np.asarray(vb_fit(x, key, cfg, use_kernel=True))
         ms = (time.perf_counter() - t0) * 1e3
         obs.set_attrs(train_device_ms=ms, route="vb_estep")
@@ -579,7 +598,7 @@ class DeviceBackend(ExecutionBackend):
         # an explicit interpret override must reach the Pallas body
         # like it does on the merge/E-step routes — use_kernel=None
         # alone would route off-TPU hosts to the jnp reference
-        with self._annotate("mlego.gibbs_sweep"):
+        with self._device_guard(), self._annotate("mlego.gibbs_sweep"):
             nkv = cgs_fit_blocked(corpus.tokens, corpus.doc_ids, cfg, key,
                                   global_nkv=global_nkv,
                                   block_docs=self.gibbs_block_docs,

@@ -1,43 +1,24 @@
-"""JAX version compatibility for the distributed layer.
-
-The repo targets the mesh APIs as they exist post-0.5 (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``) but CI pins ``jax==0.4.37``,
-where ``shard_map`` still lives in ``jax.experimental`` (with
-``check_rep`` instead of ``check_vma``) and ``make_mesh`` takes no
-``axis_types``.  Everything that must actually *run* on the pinned
-version — the vocab-sharded merge path and its multi-device tests —
-routes through these shims instead of feature-detecting inline.
-"""
+"""Mesh helpers shared by the distributed layer."""
 from __future__ import annotations
 
 from typing import Sequence
 
 import jax
 
-try:                                    # jax >= 0.5
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, any jax version.
+    """``jax.shard_map`` with replication checking off.
 
     The merge collectives psum *inside* the body and return per-shard
     slices; the static replication checker can't see through the Pallas
-    call, so both API generations run with it disabled.
+    call.
     """
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: False})
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where the API has them."""
-    try:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -1,27 +1,27 @@
 """Pallas TPU kernel: doc-blocked collapsed-Gibbs sweep.
 
-One grid step owns one *doc block* and keeps the whole sampler state
-on-chip: the block's token assignments ``z`` (1, T) and its exact
-document-topic counts ``n_kd`` (BD, K) live in VMEM for the entire
-sweep, while every block samples against the same frozen per-sweep
-snapshot of the topic-word counts (``prior`` = local n_kv + global
-N_kv + β — the DSGS Eq. 8 fixed-prior approximation applied across
-blocks).  Per token:
+One grid row owns one *doc block* and keeps its exact document-topic
+counts ``n_kd`` (BD, K) in VMEM for the whole sweep, while every block
+samples against the same frozen per-sweep snapshot of the topic-word
+counts (``prior`` = local n_kv + global N_kv + β — the DSGS Eq. 8
+fixed-prior approximation applied across blocks).  Per token:
 
     oh      = onehot(z_t)                    (VPU compare on the K lane)
     p       = (n_kd[d] − oh + α)(prior[:,w] − oh)/(prior_k − oh)
-    z_t     = inverse-CDF sample via cumsum + count(c < u·Σp)
-    n_kd[d] += onehot(z_t) − oh              (dynamic_update_slice)
+    z_t     = inverse-CDF sample: count(prefix_sum(p) < u·Σp)
+    n_kd[d] += onehot(z_t) − oh              (one-row ref store)
 
-and the block streams its new token counts into a revisited (K, V)
-output block (grid is sequential on TPU, so the accumulation is
-race-free — same pattern as vb_estep's sstats).
-
-The topic-word snapshot is passed *transposed* as ``prior_t`` (V, K)
-so the per-token gather is a (1, K) dynamic row slice on the lane
-axis, not a strided column read.  Uniforms are precomputed outside
-(one (B, T) array per sweep) — sampling stays bit-identical to the
-jnp reference.
+The per-token scalars (word, local doc, mask, uniform, assignment) are
+SMEM blocks of ``block_t`` tokens; the grid is (doc blocks, token
+chunks), with the chunk axis innermost so a block's ``n_kd`` stays
+resident across its chunks.  The topic-word snapshot is passed
+*transposed* as ``prior_t`` (V, K) so the per-token gather is a (1, K)
+row read on the lane axis.  Mosaic has no cumsum, so the prefix sum is
+the log-step shifted-add scan of ``ref.lane_prefix_sum`` — the
+reference runs the same adds, which keeps sampling bit-identical to it.
+Uniforms are precomputed outside (one (B, T) array per sweep); the new
+assignments' (K, V) counts are reduced after the kernel
+(``ref.token_counts``), as in the reference.
 """
 from __future__ import annotations
 
@@ -30,94 +30,90 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.gibbs_sweep.ref import lane_prefix_sum
 
 
 def _kernel(words_ref, ldoc_ref, mask_ref, u_ref, z_ref, nkd_ref,
-            prior_t_ref, priork_ref, z_out, nkd_out, nkv_out,
-            *, alpha: float, k_real: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+            prior_t_ref, priork_ref, z_out, nkd_out,
+            *, alpha: float, k_real: int, block_t: int):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        nkv_out[...] = jnp.zeros_like(nkv_out)
+        nkd_out[...] = nkd_ref[...]
 
-    words = words_ref[...]            # (1, T) i32
-    ldoc = ldoc_ref[...]              # (1, T) i32
-    mask = mask_ref[...]              # (1, T) f32
-    u = u_ref[...]                    # (1, T) f32
-    prior_t = prior_t_ref[...]        # (V, K) f32
-    prior_k = priork_ref[...]         # (1, K) f32
-
-    t_len = words.shape[1]
-    k = prior_t.shape[1]
+    k = prior_t_ref.shape[1]
     kiota = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-    valid = (kiota < k_real).astype(jnp.float32)
+    valid = kiota < k_real
+    prior_k = priork_ref[...]                                    # (1, K)
 
     def token(t, carry):
-        z, nkd = carry                # (1, T) i32, (1, BD, K) f32
-        w = words[0, t]
-        d = ldoc[0, t]
-        m = mask[0, t]
-        old = z[0, t]
+        w = words_ref[t]
+        d = ldoc_ref[t]
+        m = mask_ref[t]
+        old = z_ref[t]
         oh_old = (kiota == old).astype(jnp.float32) * m          # (1, K)
-        nd = jax.lax.dynamic_slice(nkd, (0, d, 0), (1, 1, k))[0] - oh_old
-        num = jax.lax.dynamic_slice(prior_t, (w, 0), (1, k)) - oh_old
+        nd = nkd_out[0, pl.ds(d, 1), :] - oh_old
+        num = prior_t_ref[pl.ds(w, 1), :] - oh_old
         den = prior_k - oh_old
-        p = valid * (nd + alpha) * num / den                     # (1, K)
-        c = jnp.cumsum(p, axis=1)
-        target = u[0, t] * c[0, k - 1]
-        new = jnp.sum((c < target).astype(jnp.int32))            # searchsorted
-        new = jnp.clip(new, 0, k_real - 1)
+        p = valid.astype(jnp.float32) * (nd + alpha) * num / den
+        c = lane_prefix_sum(p, roll=pltpu.roll)
+        total = jnp.sum(jnp.where(kiota == k_real - 1, c, 0.0),
+                        axis=1, keepdims=True)                   # c[K-1]
+        target = u_ref[t] * total
+        new = jnp.sum(jnp.logical_and(valid, c < target).astype(jnp.int32))
+        new = jnp.minimum(new, k_real - 1)
         new = jnp.where(m > 0, new, old)
         oh_new = (kiota == new).astype(jnp.float32) * m
-        nkd = jax.lax.dynamic_update_slice(
-            nkd, (nd + oh_new)[None], (0, d, 0))
-        z = jax.lax.dynamic_update_slice(
-            z, new.reshape(1, 1).astype(z.dtype), (0, t))
-        # stream the new assignment's count into the shared reduction
-        cur = pl.load(nkv_out, (pl.ds(new, 1), pl.ds(w, 1)))
-        pl.store(nkv_out, (pl.ds(new, 1), pl.ds(w, 1)), cur + m)
-        return z, nkd
+        nkd_out[0, pl.ds(d, 1), :] = nd + oh_new
+        z_out[t] = new
+        return carry
 
-    z, nkd = jax.lax.fori_loop(0, t_len, token,
-                               (z_ref[...], nkd_ref[...]))
-    z_out[...] = z
-    nkd_out[...] = nkd
+    jax.lax.fori_loop(0, block_t, token, 0)
+
+
+def _vmem_bytes(bd: int, k: int, v: int) -> int:
+    """Double-buffered VMEM blocks: the (V, K) snapshot, the (BD, K)
+    count blocks in and out, and the (1, K) row sums."""
+    return 2 * 4 * (v * k + 2 * bd * k + 8 * k)
 
 
 def gibbs_sweep_pallas(words, ldoc, mask, u, z, nkd, prior_t, prior_k,
-                       alpha: float, k_real: int, *,
+                       alpha: float, k_real: int, *, block_t: int,
                        interpret: bool = False):
-    """One blocked CGS sweep; grid = doc blocks.
+    """One blocked CGS sweep; grid = (doc blocks, token chunks).
 
-    words/ldoc/mask/u/z: (B, T); nkd: (B, BD, K); prior_t: (V, K)
-    transposed snapshot (+global +β); prior_k: (1, K) row sums.
-    Returns (z', nkd', nkv (K, V)) — nkv is the new assignments' token
-    counts summed over all blocks.
+    words/ldoc/mask/u/z: (B, T) with T a multiple of ``block_t``;
+    nkd: (B, BD, K); prior_t: (V, K) transposed snapshot (+global +β);
+    prior_k: (1, K) row sums.  Returns (z', nkd').
     """
     b, t = words.shape
     _, bd, k = nkd.shape
     v = prior_t.shape[0]
-    kernel = functools.partial(_kernel, alpha=alpha, k_real=k_real)
-    row = pl.BlockSpec((1, t), lambda i: (i, 0))
-    return pl.pallas_call(
+    n_chunks = t // block_t
+    kernel = functools.partial(_kernel, alpha=alpha, k_real=k_real,
+                               block_t=block_t)
+    # per-token scalars ride flat: a 1-D SMEM block of block_t tokens
+    tokens = pl.BlockSpec((block_t,), lambda i, j: (i * n_chunks + j,),
+                          memory_space=pltpu.SMEM)
+    counts = pl.BlockSpec((1, bd, k), lambda i, j: (i, 0, 0))
+    z_new, nkd_new = pl.pallas_call(
         kernel,
-        grid=(b,),
+        grid=(b, n_chunks),
         in_specs=[
-            row, row, row, row, row,
-            pl.BlockSpec((1, bd, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((v, k), lambda i: (0, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
+            tokens, tokens, tokens, tokens, tokens, counts,
+            pl.BlockSpec((v, k), lambda i, j: (0, 0)),
+            pl.BlockSpec((1, k), lambda i, j: (0, 0)),
         ],
-        out_specs=[
-            row,
-            pl.BlockSpec((1, bd, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((k, v), lambda i: (0, 0)),   # revisited: accumulate
-        ],
+        out_specs=[tokens, counts],
         out_shape=[
-            jax.ShapeDtypeStruct((b, t), z.dtype),
+            jax.ShapeDtypeStruct((b * t,), z.dtype),
             jax.ShapeDtypeStruct((b, bd, k), jnp.float32),
-            jax.ShapeDtypeStruct((k, v), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_bytes(bd, k, v) + (8 << 20)),
         interpret=interpret,
-    )(words, ldoc, mask, u, z, nkd, prior_t, prior_k)
+    )(words.ravel(), ldoc.ravel(), mask.ravel(), u.ravel(), z.ravel(), nkd,
+      prior_t, prior_k)
+    return z_new.reshape(b, t), nkd_new

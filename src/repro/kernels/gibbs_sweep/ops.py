@@ -10,12 +10,11 @@ from Σ tokens to max tokens-per-block).  Interpret-mode Pallas would
 serialize the grid and forfeit exactly that win, so it is reserved for
 the kernel-exercising CI leg.
 
-The kernel path pads K/V/T/BD to tile alignment (K, T lane-padded to
-128; V, BD sublane-padded to 8 — V also to 128 for the (K, V) count
-output) and strips the padding on the way out; pad topics are masked
-out of the conditional (``k_real``), pad tokens carry zero mask, and
-the snapshot is fed to the kernel transposed as (V, K) so the
-per-token topic gather is a lane-aligned row slice.
+The kernel path pads K to 128 lanes, BD to 8 sublanes and T to whole
+SMEM token chunks, and strips the padding on the way out; pad topics
+are masked out of the conditional (``k_real``), pad tokens carry zero
+mask, and the snapshot is fed to the kernel transposed as (V, K) so
+the per-token topic gather is a lane-aligned row read.
 """
 from __future__ import annotations
 
@@ -27,7 +26,7 @@ import jax.numpy as jnp
 
 from repro.kernels.common import default_interpret, interpret_forced, on_tpu
 from repro.kernels.gibbs_sweep.gibbs_sweep import gibbs_sweep_pallas
-from repro.kernels.gibbs_sweep.ref import gibbs_sweep_ref
+from repro.kernels.gibbs_sweep.ref import gibbs_sweep_ref, token_counts
 
 
 def _round_up(x: int, m: int) -> int:
@@ -61,26 +60,24 @@ def gibbs_sweep(words, ldoc, mask, u, z, nkd, prior, prior_k,
     interpret = default_interpret(interpret)
     b, t = words.shape
     bd = nkd.shape[1]
-    kp, vp = _round_up(k, 128), _round_up(v, 128)
-    tp, bdp = _round_up(t, 128), _round_up(bd, 8)
+    kp, bdp = _round_up(k, 128), _round_up(bd, 8)
+    # tokens run in SMEM chunks of 1024 (XLA's tile for a 1-D SMEM
+    # operand); pad tokens carry mask 0
+    block_t = 1024
+    tp = _round_up(t, block_t)
     # named scope: HLO metadata + jax.profiler timelines attribute the
     # launch to the MLego op by name
     with jax.named_scope("mlego.gibbs_sweep"):
-        if (kp, vp, tp, bdp) != (k, v, t, bd):
-            pad_row = ((0, 0), (0, tp - t))
-            words = jnp.pad(words, pad_row)
-            ldoc = jnp.pad(ldoc, pad_row)
-            mask = jnp.pad(mask, pad_row)
-            u = jnp.pad(u, pad_row)
-            z = jnp.pad(z, pad_row)
-            nkd = jnp.pad(nkd, ((0, 0), (0, bdp - bd), (0, kp - k)))
-            # pad topics/words carry 1.0 so den stays finite; they are
-            # masked out of the conditional via k_real and never sampled
-            prior = jnp.pad(prior, ((0, kp - k), (0, vp - v)),
-                            constant_values=1.0)
-            prior_k = jnp.pad(prior_k, (0, kp - k), constant_values=1.0)
-        z_new, nkd_new, nkv = gibbs_sweep_pallas(
-            words, ldoc, mask, u, z, nkd,
-            jnp.transpose(prior), prior_k.reshape(1, kp),
-            alpha, k, interpret=interpret)
-        return z_new[:, :t], nkd_new[:, :bd, :k], nkv[:k, :v]
+        pad_row = ((0, 0), (0, tp - t))
+        # pad topics carry 1.0 so den stays finite; they are masked out
+        # of the conditional via k_real and never sampled
+        z_new, nkd_new = gibbs_sweep_pallas(
+            jnp.pad(words, pad_row), jnp.pad(ldoc, pad_row),
+            jnp.pad(mask, pad_row), jnp.pad(u, pad_row), jnp.pad(z, pad_row),
+            jnp.pad(nkd, ((0, 0), (0, bdp - bd), (0, kp - k))),
+            jnp.pad(prior, ((0, kp - k), (0, 0)), constant_values=1.0).T,
+            jnp.pad(prior_k, (0, kp - k), constant_values=1.0).reshape(1, kp),
+            alpha, k, block_t=block_t, interpret=interpret)
+        z_new = z_new[:, :t]
+        return (z_new, nkd_new[:, :bd, :k],
+                token_counts(z_new, words, mask, k, v))

@@ -23,6 +23,30 @@ import jax
 import jax.numpy as jnp
 
 
+def lane_prefix_sum(p, roll=jnp.roll):
+    """Inclusive prefix sum over the last axis by log-step shifted adds.
+
+    The Pallas kernel runs the same adds with ``pltpu.roll`` (Mosaic
+    lowers no cumsum), so kernel and reference round identically.
+    Lanes past a vector's true length only ever add to later lanes, so
+    a K-lane prefix is the same whether the vector is padded or not.
+    """
+    k = p.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, p.shape, p.ndim - 1)
+    s = 1
+    while s < k:
+        p = p + jnp.where(lane >= s, roll(p, s, p.ndim - 1), 0.0)
+        s *= 2
+    return p
+
+
+def token_counts(z, words, mask, k: int, v: int):
+    """(K, V) token counts of the assignments ``z`` (pad tokens carry
+    mask 0)."""
+    return jnp.zeros((k, v), jnp.float32).at[
+        z.ravel(), words.ravel()].add(mask.ravel())
+
+
 def _sweep_block(words, ldoc, mask, u, z, nkd, prior, prior_k,
                  alpha: float, k_real: int):
     """Resample one doc block's tokens sequentially.
@@ -46,9 +70,10 @@ def _sweep_block(words, ldoc, mask, u, z, nkd, prior, prior_k,
         num = prior[:, w] - oh_old                # stale n_kv, own token out
         den = prior_k - oh_old
         p = valid * (nd + alpha) * num / den      # Eq. 7 w/ DSGS prior
-        c = jnp.cumsum(p)
-        new = jnp.searchsorted(c, u[t] * c[-1])
-        new = jnp.clip(new, 0, k_real - 1)
+        c = lane_prefix_sum(p)
+        target = u[t] * c[k_real - 1]
+        new = jnp.sum(((c < target) & (kidx < k_real)).astype(jnp.int32))
+        new = jnp.minimum(new, k_real - 1)
         new = jnp.where(m > 0, new, old).astype(z.dtype)
         oh_new = (kidx == new).astype(jnp.float32) * m
         nkd = nkd.at[d].add(oh_new - oh_old)
@@ -74,6 +99,4 @@ def gibbs_sweep_ref(words, ldoc, mask, u, z, nkd, prior, prior_k,
     block = functools.partial(_sweep_block, alpha=alpha, k_real=k_real)
     z, nkd = jax.vmap(block, in_axes=(0, 0, 0, 0, 0, 0, None, None))(
         words, ldoc, mask, u, z, nkd, prior, prior_k)
-    nkv = jnp.zeros((k, v), jnp.float32).at[
-        z.ravel(), words.ravel()].add(mask.ravel())
-    return z, nkd, nkv
+    return z, nkd, token_counts(z, words, mask, k, v)

@@ -28,7 +28,11 @@ def vb_estep(x, exp_elog_beta, gamma0, alpha: float, n_iters: int,
     interpret = default_interpret(interpret)
     d, v = x.shape
     k = exp_elog_beta.shape[0]
-    kp, vp = _round_up(k, 128), _round_up(v, 128)
+    kp = _round_up(k, 128)
+    # V pads to a whole number of the kernel's V chunks (pad columns
+    # carry x = 0, so they add nothing to any reduction)
+    bv = min(512, _round_up(v, 128))
+    vp = _round_up(v, bv)
     # D must pad to a whole number of doc blocks: a ragged boundary
     # block would stream out-of-bounds rows into the sstats reduction
     # (x pads are zero, so whole pad blocks contribute nothing).
@@ -48,5 +52,5 @@ def vb_estep(x, exp_elog_beta, gamma0, alpha: float, n_iters: int,
                              constant_values=alpha)
         gamma, sstats = vb_estep_pallas(x, exp_elog_beta, gamma0, alpha,
                                         n_iters, block_d=block_d,
-                                        interpret=interpret)
+                                        block_v=bv, interpret=interpret)
         return gamma[:d, :k], sstats[:k, :v]

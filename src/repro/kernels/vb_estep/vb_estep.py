@@ -13,9 +13,12 @@ and finally accumulates this block's sufficient statistics
 into a revisited output block (grid is sequential on TPU, so the
 accumulation is race-free).
 
-Tiling: BD documents × full V in VMEM.  K is padded to 128 (MXU lane),
-V to a 128 multiple.  The digamma is an 8-term shift + asymptotic
-series — pure VPU ops, no transcendental table lookups.
+Tiling: BD documents × full V in VMEM, with every V reduction run as
+a loop over 512-wide V chunks so no (BD, V) intermediate is ever
+whole; the scoped-VMEM limit is set from the block shapes.  K is
+padded to 128 (MXU lane), V to a whole number of chunks.  The digamma
+is an 8-term shift + asymptotic series — pure VPU ops, no
+transcendental table lookups.
 """
 from __future__ import annotations
 
@@ -24,6 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _digamma(x):
@@ -45,43 +49,87 @@ def _exp_dirichlet(g):
     return jnp.exp(_digamma(g) - _digamma(g.sum(-1, keepdims=True)))
 
 
+def _contract_v(a, b):
+    """(M, V) x (N, V) -> (M, N): contract the shared vocab axis."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _kernel(x_ref, eeb_ref, g0_ref, gamma_out, sstats_out, *, alpha: float,
-            n_iters: int):
+            n_iters: int, block_v: int):
     i = pl.program_id(0)
-    x = x_ref[...]
-    eeb = eeb_ref[...]
+    bd, v = x_ref.shape
+    k = eeb_ref.shape[0]
+    n_chunks = v // block_v
+
+    def chunk(j):
+        sl = pl.ds(pl.multiple_of(j * block_v, block_v), block_v)
+        return x_ref[:, sl], eeb_ref[:, sl], sl
+
+    def ratio_dot(eet):
+        # Σ_v (x / phinorm)[d, v] · eeβ[k, v], one V chunk at a time so
+        # the (BD, V) intermediates never materialize whole
+        def body(j, acc):
+            x, eeb, _ = chunk(j)
+            phinorm = jnp.dot(eet, eeb,
+                              preferred_element_type=jnp.float32) + 1e-30
+            return acc + _contract_v(x / phinorm, eeb)
+        return jax.lax.fori_loop(0, n_chunks, body,
+                                 jnp.zeros((bd, k), jnp.float32))
 
     def body(_, gamma):
         eet = _exp_dirichlet(gamma)
-        phinorm = jnp.dot(eet, eeb, preferred_element_type=jnp.float32) + 1e-30
-        ratio = x / phinorm
-        gamma = alpha + eet * jnp.dot(ratio, eeb.T,
-                                      preferred_element_type=jnp.float32)
-        return gamma
+        return alpha + eet * ratio_dot(eet)
 
     gamma = jax.lax.fori_loop(0, n_iters, body, g0_ref[...])
-    eet = _exp_dirichlet(gamma)
-    phinorm = jnp.dot(eet, eeb, preferred_element_type=jnp.float32) + 1e-30
-    part = jnp.dot(eet.T, x / phinorm,
-                   preferred_element_type=jnp.float32) * eeb
     gamma_out[...] = gamma
+    eet = _exp_dirichlet(gamma)
+    eet_t = eet.T                                    # (K, BD)
 
     @pl.when(i == 0)
     def _init():
         sstats_out[...] = jnp.zeros_like(sstats_out)
 
-    sstats_out[...] += part
+    def acc_sstats(j, carry):
+        x, eeb, sl = chunk(j)
+        phinorm = jnp.dot(eet, eeb,
+                          preferred_element_type=jnp.float32) + 1e-30
+        sstats_out[:, sl] += jnp.dot(
+            eet_t, x / phinorm, preferred_element_type=jnp.float32) * eeb
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, acc_sstats, 0)
+
+
+def _vmem_bytes(bd: int, k: int, v: int, block_v: int) -> int:
+    """VMEM the kernel needs: double-buffered (BD, V), (K, V) in/out
+    blocks and (BD, K) blocks, plus the per-chunk intermediates."""
+    f32 = 4
+    blocks = 2 * f32 * (bd * v + 2 * k * v + 2 * bd * k)
+    chunk = f32 * (3 * bd * block_v + 2 * k * block_v + 4 * bd * k)
+    return blocks + chunk
 
 
 def vb_estep_pallas(x, exp_elog_beta, gamma0, alpha: float, n_iters: int,
-                    *, block_d: int = 128, interpret: bool = False):
-    """x: (D, V) f32; exp_elog_beta: (K, V) f32; gamma0: (D, K) f32."""
+                    *, block_d: int = 128, block_v: int = 512,
+                    interpret: bool = False):
+    """x: (D, V) f32; exp_elog_beta: (K, V) f32; gamma0: (D, K) f32.
+
+    D must be a multiple of ``block_d`` (or smaller than it) and V of
+    ``block_v``; ops.vb_estep pads to both.
+    """
     d, v = x.shape
     k = exp_elog_beta.shape[0]
     bd = min(block_d, d)
+    bv = min(block_v, v)
     n_blocks = pl.cdiv(d, bd)
+    # the whole-V blocks exceed the default scoped VMEM at realistic
+    # vocabularies; ask for what the shapes need (plus headroom for
+    # Mosaic's own temporaries)
+    vmem = _vmem_bytes(bd, k, v, bv) + (8 << 20)
 
-    kernel = functools.partial(_kernel, alpha=alpha, n_iters=n_iters)
+    kernel = functools.partial(_kernel, alpha=alpha, n_iters=n_iters,
+                               block_v=bv)
     gamma, sstats = pl.pallas_call(
         kernel,
         grid=(n_blocks,),
@@ -98,6 +146,8 @@ def vb_estep_pallas(x, exp_elog_beta, gamma0, alpha: float, n_iters: int,
             jax.ShapeDtypeStruct((d, k), jnp.float32),
             jax.ShapeDtypeStruct((k, v), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
         interpret=interpret,
     )(x, exp_elog_beta, gamma0)
     return gamma, sstats

@@ -271,6 +271,45 @@ def test_session_surfaces_permanent_fault_immediately(corpus):
     assert sess.retry.total_retries == 0
 
 
+@pytest.mark.parametrize("failure", ["lowering", "compile"])
+def test_device_kernel_failure_is_permanent_and_never_replayed(
+        corpus, monkeypatch, failure):
+    """A kernel the device cannot lower or compile fails the query: no
+    retry and no host replay, which would hide a broken device path.
+    Real device loss keeps its fallback chain."""
+    import jax
+    import repro.api.backend as backend_mod
+    from repro.api import DeviceBackend
+
+    hi = _hi(corpus)
+    spec = QuerySpec(sigma=Interval(0.0, hi / 2))
+    if failure == "lowering":
+        # a real refusal: compiled Pallas does not lower for the CPU
+        backend = DeviceBackend(interpret=False)
+    else:
+        backend = DeviceBackend()
+
+        def refused(*args, **kwargs):
+            raise jax.errors.JaxRuntimeError(
+                "INVALID_ARGUMENT: Mosaic failed to compile TPU kernel")
+        monkeypatch.setattr(backend_mod, "merge_topics", refused)
+    sess = MLegoSession(corpus, CFG, seed=0, backend=backend,
+                        retry=RetryPolicy(base_delay_s=0.0))
+    sess.train_range(0.0, hi / 2)
+    with pytest.raises(PermanentExecutionError,
+                       match=r"failed to (lower|compile)"):
+        sess.submit(spec)
+    assert sess.retry.total_retries == 0
+    assert not backend.quarantined
+    assert backend.stats.host_fallbacks == 0
+
+    with injected(FaultRule("backend.merge.device", rate=1.0,
+                            kind="device_lost", max_failures=1), seed=2):
+        rep = sess.submit(spec)
+    assert rep.fallback_from == "device" and rep.backend == "host"
+    assert backend.quarantined
+
+
 # ---------------------------------------------------------------------------
 # crash-safe store: checksums, quarantine, planning around the hole
 # ---------------------------------------------------------------------------

@@ -17,7 +17,10 @@ RNG = np.random.default_rng(42)
                                    # D > block_d and not a block multiple:
                                    # regression for the ragged boundary
                                    # block reading garbage into sstats
-                                   (135, 150, 6), (300, 192, 12)])
+                                   (135, 150, 6), (300, 192, 12),
+                                   # V over several kernel V chunks and
+                                   # not a chunk multiple
+                                   (40, 1100, 20)])
 def test_vb_estep_kernel(d, v, k):
     from repro.kernels.vb_estep.ops import vb_estep
     from repro.kernels.vb_estep.ref import vb_estep_ref

@@ -29,6 +29,8 @@ RNG = np.random.default_rng(23)
 
 def run_sub(body: str, devices: int = 8, timeout: int = 420):
     env = dict(os.environ)
+    # virtual CPU devices: the child must never contend for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["MLEGO_KERNEL_INTERPRET"] = "1"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")

@@ -16,6 +16,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_sub(body: str, devices: int = 8, timeout: int = 420):
     env = dict(os.environ)
+    # virtual CPU devices: the child must never contend for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     proc = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
