@@ -47,7 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 
 from repro.api.trainers import (
     TrainerFn,
@@ -78,7 +78,6 @@ from repro.kernels.merge_topics.ops import (
     merge_topics_ragged,
     segment_ids,
 )
-from repro.obs import profile as obs_profile
 from repro.obs import trace as obs
 from repro.testing.faults import maybe_fail
 
@@ -156,11 +155,6 @@ class ExecutionBackend:
         # health: a quarantined backend is suspected of device loss;
         # sessions route around it until a breaker probe re-admits it
         self.quarantined = False
-        # opt-in kernel profiling (see repro.obs.profile): wraps
-        # launches in jax.profiler annotations and lands HLO-derived
-        # flops/bytes on the ambient span.  Costs one compile per new
-        # launch shape — keep off on latency-sensitive paths.
-        self.profile = False
 
     # -- health ----------------------------------------------------------
     def quarantine(self) -> None:
@@ -411,6 +405,9 @@ class DeviceBackend(ExecutionBackend):
     gibbs_block_docs : documents per sampler block on the gs route
                  (more blocks = shorter sequential chain, slightly
                  staler topic-word counts within a sweep)
+    profile    : accepted so one profiling flag can be passed to every
+                 layer; the mirror of spans onto the profiler belongs
+                 to the tracer of the owning session or service
 
     Every other kind falls back to the host trainer registry.  Fresh
     gap models are *warm-inserted* into the LRU (``note_trained``) so
@@ -433,7 +430,6 @@ class DeviceBackend(ExecutionBackend):
         self.kernel_estep = kernel_estep
         self.kernel_gibbs = kernel_gibbs
         self.gibbs_block_docs = gibbs_block_docs
-        self.profile = profile
         self._store: Optional[ModelStore] = None
 
     def _make_cache(self, capacity: int,
@@ -471,10 +467,6 @@ class DeviceBackend(ExecutionBackend):
         maybe_fail(f"backend.fetch.{self.name}")
         return self.cache.get(model, stat_key)
 
-    def _annotate(self, name: str):
-        """Profiler annotation for a launch; no-op unless profiling."""
-        return obs_profile.annotate(name) if self.profile else nullcontext()
-
     # -- merge -----------------------------------------------------------
     def merge(self, parts, kind, cfg):
         maybe_fail(f"backend.merge.{self.name}")
@@ -487,21 +479,21 @@ class DeviceBackend(ExecutionBackend):
         with self._device_guard(), \
                 obs.span("kernel.launch", "backend", op="merge_topics",
                          n_parts=len(parts), backend=self.name):
-            stats = jnp.stack([self._fetch(m, stat_key) for m in parts])
-            w = jnp.ones((len(parts),), jnp.float32)
-            with self._annotate("mlego.merge_topics"):
+            with obs.span("merge.stack", "backend"):
+                stats = jnp.stack([self._fetch(m, stat_key)
+                                   for m in parts])
+            with obs.span("merge.kernel", "backend"):
+                w = jnp.ones((len(parts),), jnp.float32)
                 merged = merge_topics(stats, w, bias=bias, base=base,
                                       interpret=self.interpret)
                 merged.block_until_ready()
             ms = (time.perf_counter() - t0) * 1e3
             obs.set_attrs(merge_device_ms=ms)
-            if self.profile:
-                obs_profile.annotate_span("hlo", obs_profile.hlo_features(
-                    "merge_topics", merge_topics, stats, w,
-                    bias=bias, base=base, interpret=self.interpret))
         self._sync_cache_counters()
         self._count(merges=1, device_launches=1, merge_device_ms=ms)
-        return finish(np.asarray(merged))
+        with obs.span("merge.finish", "backend",
+                      bytes_out=int(merged.nbytes)):
+            return finish(np.asarray(merged))
 
     def merge_many(self, part_lists, kind, cfg):
         """§V.C batch merge stage: one ragged segmented launch.
@@ -525,11 +517,13 @@ class DeviceBackend(ExecutionBackend):
                          op="merge_topics_ragged",
                          n_plans=len(part_lists), backend=self.name):
             stats_list, weights_list = [], []
-            for parts in part_lists:
-                stats_list.append(
-                    jnp.stack([self._fetch(m, stat_key) for m in parts]))
-                weights_list.append(jnp.ones((len(parts),), jnp.float32))
-            with self._annotate("mlego.merge_topics_ragged"):
+            with obs.span("merge.stack", "backend"):
+                for parts in part_lists:
+                    stats_list.append(jnp.stack(
+                        [self._fetch(m, stat_key) for m in parts]))
+                    weights_list.append(
+                        jnp.ones((len(parts),), jnp.float32))
+            with obs.span("merge.kernel", "backend"):
                 merged, pad_rows, launches = merge_topics_ragged(
                     stats_list, weights_list, bias=bias, base=base,
                     interpret=self.interpret)
@@ -545,7 +539,9 @@ class DeviceBackend(ExecutionBackend):
         self._count(merges=len(part_lists), device_launches=launches,
                     merge_device_ms=ms, pad_rows=pad_rows,
                     pad_bytes=pad_rows * row_nbytes)
-        return [finish(np.asarray(row)) for row in merged]
+        with obs.span("merge.finish", "backend",
+                      bytes_out=sum(int(row.nbytes) for row in merged)):
+            return [finish(np.asarray(row)) for row in merged]
 
     def _sync_cache_counters(self) -> None:
         c = self.cache
@@ -582,9 +578,17 @@ class DeviceBackend(ExecutionBackend):
                          key) -> Dict[str, np.ndarray]:
         from repro.core.vb import vb_fit
         t0 = time.perf_counter()
-        x = doc_term_matrix(corpus)
-        with self._device_guard(), self._annotate("mlego.vb_estep"):
-            lam = np.asarray(vb_fit(x, key, cfg, use_kernel=True))
+        with obs.span("train.densify", "backend", d=corpus.n_docs):
+            x = doc_term_matrix(corpus)
+        with self._device_guard():
+            # the wait for λ sits in train.fit; the copy back is all
+            # that is left for train.fetch
+            with obs.span("train.fit", "backend", bytes_in=int(x.nbytes)):
+                lam = vb_fit(jax.device_put(x), key, cfg, use_kernel=True)
+                lam.block_until_ready()
+            with obs.span("train.fetch", "backend",
+                          bytes_out=int(lam.nbytes)):
+                lam = np.asarray(lam)
         ms = (time.perf_counter() - t0) * 1e3
         obs.set_attrs(train_device_ms=ms, route="vb_estep")
         self._count(gap_device_trains=1, train_device_ms=ms)
@@ -598,7 +602,7 @@ class DeviceBackend(ExecutionBackend):
         # an explicit interpret override must reach the Pallas body
         # like it does on the merge/E-step routes — use_kernel=None
         # alone would route off-TPU hosts to the jnp reference
-        with self._device_guard(), self._annotate("mlego.gibbs_sweep"):
+        with self._device_guard():
             nkv = cgs_fit_blocked(corpus.tokens, corpus.doc_ids, cfg, key,
                                   global_nkv=global_nkv,
                                   block_docs=self.gibbs_block_docs,
@@ -681,9 +685,11 @@ class ShardedDeviceBackend(DeviceBackend):
                 obs.span("kernel.launch", "backend",
                          op="merge_topics_sharded", n_parts=len(parts),
                          backend=self.name, shards=self.shards):
-            stats = jnp.stack([self._fetch(m, stat_key) for m in parts])
-            w = jnp.ones((len(parts),), jnp.float32)
-            with self._annotate("mlego.merge_topics_sharded"):
+            with obs.span("merge.stack", "backend"):
+                stats = jnp.stack([self._fetch(m, stat_key)
+                                   for m in parts])
+            with obs.span("merge.kernel", "backend"):
+                w = jnp.ones((len(parts),), jnp.float32)
                 beta = merge_topics_sharded(
                     stats, w, self.env, bias=bias, base=base,
                     num_offset=device_norm_offset(fam, cfg), v_true=v_true,
@@ -694,7 +700,7 @@ class ShardedDeviceBackend(DeviceBackend):
         self._sync_cache_counters()
         self._count(merges=1, device_launches=1, merge_device_ms=ms)
         with obs.span("allgather", "backend", backend=self.name,
-                      bytes=int(beta.nbytes), shards=self.shards):
+                      bytes_out=int(beta.nbytes), shards=self.shards):
             host = np.asarray(beta)
         return host[:, :v_true]
 
@@ -714,11 +720,12 @@ class ShardedDeviceBackend(DeviceBackend):
                          op="merge_topics_ragged_sharded",
                          n_plans=len(part_lists), backend=self.name,
                          shards=self.shards):
-            rows = [self._fetch(m, stat_key)
-                    for parts in part_lists for m in parts]
-            stats = jnp.stack(rows)
-            w = jnp.ones((len(rows),), jnp.float32)
-            with self._annotate("mlego.merge_topics_ragged_sharded"):
+            with obs.span("merge.stack", "backend"):
+                rows = [self._fetch(m, stat_key)
+                        for parts in part_lists for m in parts]
+                stats = jnp.stack(rows)
+            with obs.span("merge.kernel", "backend"):
+                w = jnp.ones((len(rows),), jnp.float32)
                 beta = merge_topics_ragged_sharded(
                     stats, w, segment_ids(counts), len(counts), self.env,
                     bias=bias, base=base,
@@ -731,7 +738,7 @@ class ShardedDeviceBackend(DeviceBackend):
         self._count(merges=len(part_lists), device_launches=1,
                     merge_device_ms=ms)
         with obs.span("allgather", "backend", backend=self.name,
-                      bytes=int(beta.nbytes), shards=self.shards):
+                      bytes_out=int(beta.nbytes), shards=self.shards):
             host = np.asarray(beta)[:, :, :v_true]
         return [host[i] for i in range(len(counts))]
 
@@ -742,7 +749,7 @@ _FACTORIES = {"host": HostBackend, "device": DeviceBackend,
 
 def make_backend(name: str, **kwargs) -> ExecutionBackend:
     """Construct a backend by name; ``kwargs`` pass to its constructor
-    (host ignores ``profile=`` — it has no launches to annotate)."""
+    (host ignores ``profile=``)."""
     try:
         factory = _FACTORIES[name]
     except KeyError:

@@ -148,6 +148,9 @@ class MLegoSession:
         # land in one exportable buffer)
         self.tracer = tracer if tracer is not None else Tracer()
         self._profile = profile
+        if profile:
+            # spans mirrored onto the profiler's host plane and clock
+            self.tracer.annotate = jax.profiler.TraceAnnotation
         # optional outcome hook: called once per answered query with
         # (answered_by_backend, fallback_from, error) — the serving
         # layer installs its breaker/health feed here so *direct*

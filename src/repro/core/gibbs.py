@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.lda_default import LDAConfig
+from repro.obs import trace as obs
 
 
 @functools.partial(jax.jit, static_argnames=("n_topics", "n_docs", "vocab",
@@ -199,20 +200,28 @@ def cgs_fit_blocked(tokens: np.ndarray, doc_ids: np.ndarray, cfg: LDAConfig,
     if tokens.size == 0:
         return np.zeros((cfg.n_topics, _vocab(cfg, global_nkv)), np.float32)
     vocab = _vocab(cfg, global_nkv)
-    gnkv = (jnp.zeros((cfg.n_topics, vocab), jnp.float32)
-            if global_nkv is None else jnp.asarray(global_nkv, jnp.float32))
-    if np.any(np.diff(doc_ids) < 0):
-        # blocked_layout needs the CSR doc-sorted stream cgs_fit does
-        # not; token order within a doc is immaterial to the sampler
-        order = np.argsort(doc_ids, kind="stable")
-        tokens, doc_ids = tokens[order], doc_ids[order]
     n_docs = int(doc_ids.max()) + 1
-    words, ldoc, mask = blocked_layout(tokens, doc_ids, n_docs, block_docs)
+    with obs.span("train.densify", "backend", d=n_docs):
+        if np.any(np.diff(doc_ids) < 0):
+            # blocked_layout needs the CSR doc-sorted stream cgs_fit
+            # does not; token order within a doc is immaterial to the
+            # sampler
+            order = np.argsort(doc_ids, kind="stable")
+            tokens, doc_ids = tokens[order], doc_ids[order]
+        host = blocked_layout(tokens, doc_ids, n_docs, block_docs)
+        if global_nkv is not None:
+            host += (np.asarray(global_nkv, np.float32),)
     use_kernel = default_use_kernel(use_kernel)
-    nkv = _blocked_sweeps(
-        jnp.asarray(words), jnp.asarray(ldoc), jnp.asarray(mask), key, gnkv,
-        cfg.n_topics, block_docs, vocab,
-        sweeps if sweeps is not None else cfg.gibbs_sweeps,
-        cfg.alpha, cfg.eta, use_kernel,
-        default_interpret(interpret) if use_kernel else False)
-    return np.asarray(nkv)
+    with obs.span("train.fit", "backend",
+                  bytes_in=sum(int(a.nbytes) for a in host)):
+        words, ldoc, mask, *prior = jax.device_put(host)
+        gnkv = prior[0] if prior else \
+            jnp.zeros((cfg.n_topics, vocab), jnp.float32)
+        nkv = _blocked_sweeps(
+            words, ldoc, mask, key, gnkv, cfg.n_topics, block_docs, vocab,
+            sweeps if sweeps is not None else cfg.gibbs_sweeps,
+            cfg.alpha, cfg.eta, use_kernel,
+            default_interpret(interpret) if use_kernel else False)
+        nkv.block_until_ready()
+    with obs.span("train.fetch", "backend", bytes_out=int(nkv.nbytes)):
+        return np.asarray(nkv)

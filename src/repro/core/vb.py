@@ -62,17 +62,25 @@ def vb_estep(x, exp_elog_beta, gamma0, alpha: float, n_iters: int,
 
 @functools.partial(jax.jit, static_argnames=("cfg", "use_kernel"))
 def vb_fit(x, key, cfg: LDAConfig, *, use_kernel: bool = False):
-    """Batch VB on a dense doc-term matrix.  Returns λ (K, V) f32."""
+    """Batch VB on a dense doc-term matrix.  Returns λ (K, V) f32.
+
+    The named scopes put each phase's ops under its name in the HLO
+    metadata (``mlego.vb_init``, ``mlego.vb_expect``, ``mlego.vb_mstep``;
+    the E-step kernel brings ``mlego.vb_estep``), so a profiler trace
+    can tell them apart."""
     k = cfg.n_topics
     d, v = x.shape
-    lam0 = jax.random.gamma(key, 100.0, (k, v), jnp.float32) * 0.01
+    with jax.named_scope("mlego.vb_init"):
+        lam0 = jax.random.gamma(key, 100.0, (k, v), jnp.float32) * 0.01
 
     def outer(lam, _):
         gamma0 = jnp.ones((d, k), jnp.float32)
-        _, sstats = vb_estep(x, _exp_dirichlet_expectation(lam), gamma0,
-                             cfg.alpha, cfg.e_step_iters,
-                             use_kernel=use_kernel)
-        lam = cfg.eta + sstats
+        with jax.named_scope("mlego.vb_expect"):
+            exp_elog_beta = _exp_dirichlet_expectation(lam)
+        _, sstats = vb_estep(x, exp_elog_beta, gamma0, cfg.alpha,
+                             cfg.e_step_iters, use_kernel=use_kernel)
+        with jax.named_scope("mlego.vb_mstep"):
+            lam = cfg.eta + sstats
         return lam, None
 
     lam, _ = jax.lax.scan(outer, lam0, None, length=cfg.max_iters)

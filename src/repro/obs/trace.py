@@ -24,6 +24,17 @@ Design rules, in priority order:
 * **Monotonic clocks.**  All timestamps are ``time.perf_counter()``
   seconds.  Chrome export rebases them onto the tracer's own epoch so
   traces from one process line up; never mix wall-clock in.
+* **One clock with the profiler.**  A tracer given an ``annotate``
+  hook (``jax.profiler.TraceAnnotation``; the session or service sets
+  it under ``profile=True``) mirrors every context-manager span into
+  an annotation of the same name, so the spans land on the profiler
+  trace's host plane beside the device ops.  ``record()`` spans are
+  intervals measured across threads and stay unmirrored.
+* **Compiles are spans.**  The first span an enabled tracer opens
+  registers one process-wide ``jax.monitoring`` listener (jax is
+  imported then, not at module import) that records each backend
+  compile as a ``jax.compile`` span under the compiling thread's
+  ambient span.
 * **Explicit parents, implicit nesting.**  Entering ``tracer.span()``
   pushes the span onto the calling thread's context stack, so nested
   spans pick up their parent automatically.  Crossing a thread (a
@@ -46,7 +57,8 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import (Any, Callable, ContextManager, Dict, Iterator, List,
+                    Optional, Tuple)
 
 __all__ = [
     "Span",
@@ -137,6 +149,43 @@ def span(name: str, cat: str = "internal", **attrs: Any):
     return tr.span(name, cat, attrs=attrs or None)
 
 
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener = False
+_compile_lock = threading.Lock()
+
+
+def _on_compile(event: str, duration: float, **kwargs: Any) -> None:
+    """``jax.monitoring`` listener: a backend compile, timed by JAX and
+    reported when it ends, becomes a ``jax.compile`` span under the
+    compiling thread's ambient span (nothing without one)."""
+    if event != _COMPILE_EVENT:
+        return
+    s = _stack()
+    if not s:
+        return
+    tr, parent = s[-1]
+    t1 = tr._clock()
+    attrs = {"fun_name": kwargs["fun_name"]} if "fun_name" in kwargs \
+        else None
+    tr.record("jax.compile", "compile", t1 - duration, t1,
+              trace_id=parent.trace_id, parent_id=parent.span_id,
+              attrs=attrs)
+
+
+def _listen_for_compiles() -> None:
+    """Register `_on_compile` once per process (a no-op without jax)."""
+    global _compile_listener
+    with _compile_lock:
+        if _compile_listener:
+            return
+        _compile_listener = True
+        try:
+            import jax.monitoring
+        except ImportError:
+            return
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+
+
 def instant(name: str, cat: str = "event", **attrs: Any) -> None:
     """Record a zero-duration event under the ambient span, if any."""
     s = _stack()
@@ -155,7 +204,9 @@ class Tracer:
     overwritten and ``dropped`` counts how many were lost (exported
     traces say so).  ``enabled=False`` turns every entry point into a
     no-op that still yields ``None`` — callers guard attribute access
-    with ``if sp is not None`` or use `set_attrs()`.
+    with ``if sp is not None`` or use `set_attrs()`.  ``annotate``, when
+    set, maps a span name to a context manager entered around each
+    ``span()`` region (the profiler mirror; see the module docstring).
     """
 
     def __init__(self, capacity: int = 16384, enabled: bool = True,
@@ -163,6 +214,7 @@ class Tracer:
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
         self.enabled = enabled
+        self.annotate: Optional[Callable[[str], ContextManager]] = None
         self.dropped = 0
         self._clock = clock
         self._epoch = clock()
@@ -194,6 +246,8 @@ class Tracer:
         if not self.enabled:
             yield None
             return
+        if not _compile_listener:
+            _listen_for_compiles()
         stack = _stack()
         if trace_id is None:
             if parent_id is None and stack:
@@ -206,20 +260,23 @@ class Tracer:
                 # trace if there is one, else mint.
                 trace_id = (stack[-1][1].trace_id if stack
                             else self.new_trace_id())
-        sp = Span(name=name, kind=kind, trace_id=trace_id,
-                  span_id=self.new_span_id(), parent_id=parent_id,
-                  t0=self._clock(), thread=threading.get_ident(),
-                  attrs=dict(attrs) if attrs else {})
-        stack.append((self, sp))
-        try:
-            yield sp
-        except BaseException as exc:
-            sp.attrs.setdefault("error", type(exc).__name__)
-            raise
-        finally:
-            stack.pop()
-            sp.t1 = self._clock()
-            self._append(sp)
+        mirror = self.annotate(name) if self.annotate is not None \
+            else _NULL_CTX
+        with mirror:
+            sp = Span(name=name, kind=kind, trace_id=trace_id,
+                      span_id=self.new_span_id(), parent_id=parent_id,
+                      t0=self._clock(), thread=threading.get_ident(),
+                      attrs=dict(attrs) if attrs else {})
+            stack.append((self, sp))
+            try:
+                yield sp
+            except BaseException as exc:
+                sp.attrs.setdefault("error", type(exc).__name__)
+                raise
+            finally:
+                stack.pop()
+                sp.t1 = self._clock()
+                self._append(sp)
 
     def record(self, name: str, kind: str, t0: float, t1: float, *,
                trace_id: str, span_id: Optional[str] = None,

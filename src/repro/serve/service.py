@@ -79,6 +79,8 @@ from concurrent.futures import Future
 from dataclasses import replace as _dc_replace
 from typing import Deque, Dict, List, Optional, Tuple, Union
 
+import jax
+
 from repro.api.backend import ExecutionBackend, make_backend
 from repro.api.planner import PlanCache
 from repro.api.session import MLegoSession
@@ -237,6 +239,9 @@ class MLegoService:
         # Prometheus exposition renders)
         self.tracer = tracer if tracer is not None else Tracer(
             capacity=65536)
+        if profile:
+            # spans mirrored onto the profiler's host plane and clock
+            self.tracer.annotate = jax.profiler.TraceAnnotation
         self.registry = registry if registry is not None \
             else MetricsRegistry()
         self._build_metrics()

@@ -4,9 +4,13 @@ snapshot), and their integration through MLegoSession / MLegoService —
 trace ids surviving coalescing and α-splits, retry instants on the
 span tree, Prometheus exposition agreeing with the same-run
 ServiceReport, the breaker fed from *direct* session use, per-query
-train_device_ms attribution, and HLO-derived span attributes under
-``profile=True``."""
+train_device_ms attribution, the phase spans of gap training and of
+the device merge, compile events as spans, and the mirror of spans
+onto the profiler's clock under ``profile=True``."""
+import glob
 import json
+
+import jax
 
 import numpy as np
 import pytest
@@ -249,18 +253,114 @@ def test_device_query_emits_kernel_spans_with_device_ms(train):
     assert cur is root
 
 
-def test_profile_mode_lands_hlo_features_on_launch_span(train):
-    sess = MLegoSession(train, CFG, seed=0, backend="device",
-                        profile=True)
+def _children(spans, parent):
+    return [s for s in spans if s.parent_id == parent.span_id]
+
+
+def test_vb_gap_training_splits_into_phase_spans(train):
+    sess = MLegoSession(train, CFG, seed=0, backend="device")
+    rep = sess.submit(QuerySpec(sigma=Interval(0.0, _hi(train) / 3)))
+    spans = sess.tracer.spans(trace_id=rep.trace)
+    (tr,) = [s for s in spans if s.name == "train"]
+    phases = {s.name: s for s in _children(spans, tr)}
+    assert list(phases) == ["train.densify", "train.fit", "train.fetch"]
+    d = phases["train.densify"].attrs["d"]
+    assert d == rep.materialized[0].n_docs
+    assert phases["train.fit"].attrs["bytes_in"] == d * CFG.vocab_size * 4
+    assert phases["train.fetch"].attrs["bytes_out"] \
+        == CFG.n_topics * CFG.vocab_size * 4
+    assert tr.t0 <= phases["train.densify"].t0 \
+        <= phases["train.fetch"].t1 <= tr.t1
+
+
+def _merge_phases(spans):
+    merges = [s for s in spans if s.name == "merge"]
+    assert len(merges) == 1
+    launch = next(s for s in spans if s.name == "kernel.launch")
+    assert launch.parent_id == merges[0].span_id
+    inner = [s.name for s in _children(spans, launch)
+             if s.name.startswith("merge.")]
+    (finish,) = [s for s in _children(spans, merges[0])
+                 if s.name == "merge.finish"]
+    assert finish.t0 >= launch.t1
+    return inner, finish
+
+
+def test_device_merge_splits_into_phase_spans(train):
+    sess = MLegoSession(train, CFG, seed=0, backend="device")
     hi = _hi(train)
     sess.train_range(0.0, hi / 2)
     sess.train_range(hi / 2, hi)
     rep = sess.submit(QuerySpec(sigma=Interval(0.0, hi), alpha=1.0))
-    launches = sess.tracer.spans(trace_id=rep.trace,
-                                 name="kernel.launch")
-    feats = [s for s in launches if "hlo_hbm_bytes" in s.attrs]
-    assert feats, "profile=True must land HLO features on the span"
-    assert feats[0].attrs["hlo_hbm_bytes"] > 0.0
+    inner, finish = _merge_phases(sess.tracer.spans(trace_id=rep.trace))
+    assert inner == ["merge.stack", "merge.kernel"]
+    assert finish.attrs["bytes_out"] == CFG.n_topics * CFG.vocab_size * 4
+
+
+def test_device_merge_many_splits_into_phase_spans(train):
+    sess = MLegoSession(train, CFG, seed=0, backend="device")
+    hi = _hi(train)
+    for a, b in ((0.0, hi / 2), (hi / 2, hi)):
+        sess.train_range(a, b)
+    br = sess.submit_many([QuerySpec(sigma=Interval(0.0, hi / 2)),
+                           QuerySpec(sigma=Interval(0.0, hi))])
+    inner, finish = _merge_phases(sess.tracer.spans(trace_id=br.trace))
+    assert inner == ["merge.stack", "merge.kernel"]
+    assert finish.attrs["bytes_out"] \
+        == 2 * CFG.n_topics * CFG.vocab_size * 4
+
+
+def _twice_plus_one(x):
+    return 2 * x + 1
+
+
+def test_fresh_jit_shape_records_one_compile_span():
+    f = jax.jit(_twice_plus_one)
+    x = np.ones(13, np.float32)
+    tr = Tracer()
+    with tr.span("root", "test") as root:
+        f(x).block_until_ready()
+        f(x).block_until_ready()              # cached: no second compile
+    (c,) = tr.spans(name="jax.compile")
+    assert c.parent_id == root.span_id and c.kind == "compile"
+    assert "_twice_plus_one" in c.attrs["fun_name"]
+    assert root.t0 <= c.t0 < c.t1 <= root.t1
+
+
+def test_disabled_tracer_registers_no_compile_listener(monkeypatch):
+    registered = []
+    monkeypatch.setattr(obs, "_compile_listener", False)
+    monkeypatch.setattr(jax.monitoring,
+                        "register_event_duration_secs_listener",
+                        registered.append)
+    tr = Tracer(enabled=False)
+    with tr.span("root", "test"):
+        jax.jit(lambda x: x - 3)(np.ones(7, np.float32))
+    assert registered == [] and len(tr.spans()) == 0
+    # an enabled tracer registers the one listener on its first span
+    on = Tracer()
+    for _ in range(2):
+        with on.span("root", "test"):
+            pass
+    assert registered == [obs._on_compile]
+
+
+def test_profile_mode_mirrors_spans_onto_the_profiler(train, tmp_path):
+    sess = MLegoSession(train, CFG, seed=0, backend="device",
+                        profile=True)
+    hi = _hi(train)
+    sess.train_range(0.0, hi / 2)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sess.submit(QuerySpec(sigma=Interval(0.0, 0.8 * hi)))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    host = {e.name for p in jax.profiler.ProfileData.from_file(path).planes
+            if p.name.startswith("/host:") for line in p.lines
+            for e in line.events}
+    assert {"train.fit", "merge.kernel", "kernel.launch"} <= host
 
 
 def test_fallback_replay_stays_in_the_query_trace(train):
