@@ -13,7 +13,7 @@ launch per query, and a single *ragged segmented* launch for a
 zero pad rows on any batch shape — this retired the power-of-two
 bucketed launcher) — and routes scratch-gap training through the
 kernel paths: VB through the fused E-step kernel
-(``vb_estep(..., use_kernel=True)``), Gibbs through the doc-blocked
+(``vb_fit(..., use_kernel=True)``), Gibbs through the doc-blocked
 CGS sweep (``cgs_fit_blocked`` / ``kernels/gibbs_sweep``).  A freshly
 trained persisted gap model is warm-inserted into the LRU
 (``note_trained``) so the merge that follows reads it back as a hit.

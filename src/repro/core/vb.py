@@ -25,16 +25,47 @@ from repro.configs.lda_default import LDAConfig
 from repro.distributed.sharding import MeshEnv
 
 
-def _exp_dirichlet_expectation(x):
-    """exp(E[log p]) for Dirichlet rows: exp(ψ(x) − ψ(Σx))."""
-    return jnp.exp(
-        jax.scipy.special.digamma(x)
-        - jax.scipy.special.digamma(x.sum(-1, keepdims=True))
-    )
+def _psi_parts(x):
+    """(z, r) with ψ(x) = log z + r, for x > 0.
+
+    ψ(x) = ψ(x + 4) − Σ_{i<4} 1/(x + i), the four reciprocals summed in
+    pairs (1/a + 1/b = (a + b)/(ab): one division a pair), and ψ(z) at
+    z = x + 4 by its asymptotic series, whose first omitted term is
+    below 2e-9 there.  No reflection branch: every argument here (λ ≥ η,
+    γ ≥ α, their sums) is positive."""
+    shift = 0.0
+    for i in (0.0, 2.0):
+        a = x + i
+        b = a + 1.0
+        shift = shift + (a + b) / (a * b)
+    z = x + 4.0
+    inv = 1.0 / z
+    inv2 = inv * inv
+    # ψ(z) − log z ≈ −1/(2z) − 1/(12z²) + 1/(120z⁴) − 1/(252z⁶)
+    #                + 1/(240z⁸) − 1/(132z¹⁰)
+    series = -0.5 * inv - inv2 * (1.0 / 12.0 - inv2 * (
+        1.0 / 120.0 - inv2 * (1.0 / 252.0 - inv2 * (
+            1.0 / 240.0 - inv2 / 132.0))))
+    return z, series - shift
 
 
-def vb_estep(x, exp_elog_beta, gamma0, alpha: float, n_iters: int,
-             *, use_kernel: bool = False):
+def _digamma(x):
+    """ψ(x) for x > 0."""
+    z, r = _psi_parts(x)
+    return jnp.log(z) + r
+
+
+def _exp_dirichlet_expectation(x, total=None):
+    """exp(E[log p]) for Dirichlet rows of positive x: exp(ψ(x) − ψ(Σx)),
+    taken as z·exp(r − ψ(Σx)) to save the log.  ``total`` stands in for
+    the row sums Σx (a V-sharded λ passes its global ones)."""
+    if total is None:
+        total = x.sum(-1, keepdims=True)
+    z, r = _psi_parts(x)
+    return z * jnp.exp(r - _digamma(total))
+
+
+def vb_estep(x, exp_elog_beta, gamma0, alpha: float, n_iters: int):
     """Coordinate-ascent E-step over a doc-block.
 
     x:              (D, V) counts, f32
@@ -43,10 +74,6 @@ def vb_estep(x, exp_elog_beta, gamma0, alpha: float, n_iters: int,
     Returns (gamma, sstats) with sstats (K, V) = Σ_d n_dw φ_dwk
     (already multiplied by expElogbeta).
     """
-    if use_kernel:
-        from repro.kernels.vb_estep import ops as _ops
-        return _ops.vb_estep(x, exp_elog_beta, gamma0, alpha, n_iters)
-
     def body(gamma, _):
         exp_elog_theta = _exp_dirichlet_expectation(gamma)  # (D, K)
         phinorm = exp_elog_theta @ exp_elog_beta + 1e-30    # (D, V)
@@ -67,18 +94,38 @@ def vb_fit(x, key, cfg: LDAConfig, *, use_kernel: bool = False):
     The named scopes put each phase's ops under its name in the HLO
     metadata (``mlego.vb_init``, ``mlego.vb_expect``, ``mlego.vb_mstep``;
     the E-step kernel brings ``mlego.vb_estep``), so a profiler trace
-    can tell them apart."""
+    can tell them apart.
+
+    On the kernel route x never changes inside the fit, so x and γ₀ are
+    padded to the kernel's layout once, before the loop; each step
+    computes E[β] over the true (K, V) entries only and pads it."""
     k = cfg.n_topics
     d, v = x.shape
     with jax.named_scope("mlego.vb_init"):
         lam0 = jax.random.gamma(key, 100.0, (k, v), jnp.float32) * 0.01
+        gamma0 = jnp.ones((d, k), jnp.float32)
+        if use_kernel:
+            from repro.kernels.vb_estep import ops as _ops
+            dp, vp, kp = _ops.padded_dims(d, v, k)
+            x = jnp.pad(x, ((0, dp - d), (0, vp - v)))
+            gamma0 = jnp.pad(gamma0, ((0, dp - d), (0, kp - k)),
+                             constant_values=cfg.alpha)
 
     def outer(lam, _):
-        gamma0 = jnp.ones((d, k), jnp.float32)
         with jax.named_scope("mlego.vb_expect"):
             exp_elog_beta = _exp_dirichlet_expectation(lam)
-        _, sstats = vb_estep(x, exp_elog_beta, gamma0, cfg.alpha,
-                             cfg.e_step_iters, use_kernel=use_kernel)
+            if use_kernel:
+                # pad topics get ~0 (tiny positive keeps phinorm finite)
+                exp_elog_beta = jnp.pad(exp_elog_beta,
+                                        ((0, kp - k), (0, vp - v)),
+                                        constant_values=1e-30)
+        if use_kernel:
+            _, sstats = _ops.vb_estep_padded(x, exp_elog_beta, gamma0,
+                                             cfg.alpha, cfg.e_step_iters)
+            sstats = sstats[:k, :v]
+        else:
+            _, sstats = vb_estep(x, exp_elog_beta, gamma0, cfg.alpha,
+                                 cfg.e_step_iters)
         with jax.named_scope("mlego.vb_mstep"):
             lam = cfg.eta + sstats
         return lam, None
@@ -118,8 +165,7 @@ def vb_fit_sharded(x, key, cfg: LDAConfig, env: MeshEnv,
             row = lam_l.sum(-1, keepdims=True)
             if tp is not None and env.tp_size > 1:
                 row = jax.lax.psum(row, tp)
-            ee_beta = jnp.exp(jax.scipy.special.digamma(lam_l)
-                              - jax.scipy.special.digamma(row))
+            ee_beta = _exp_dirichlet_expectation(lam_l, row)
             gamma = jnp.ones((d_l, k), jnp.float32)
 
             def estep(gamma, _):
